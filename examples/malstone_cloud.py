@@ -14,12 +14,13 @@ import jax
 import numpy as np
 
 from repro.core import malstone_run, malstone_single_device
+from repro.launch.mesh import make_mesh
 from repro.malgen import MalGenConfig, generate_sharded_log
 
 
 def main():
     nodes = jax.device_count()
-    mesh = jax.make_mesh((nodes,), ("data",))
+    mesh = make_mesh((nodes,), ("data",))
     cfg = MalGenConfig(num_sites=10_000, num_entities=100_000)
     rps = 262_144
     print(f"MalGen: {nodes} nodes x {rps} records "

@@ -44,10 +44,10 @@ _LOG_ENGINES = ("oneshot", "streaming")
 _SEED_ENGINES = ("generated", "generated_streaming")
 
 # Primitives that force a device<->host round trip when staged inside a
-# jitted computation. ``debug_callback`` is deliberately included: a
-# forgotten jax.debug.print in a driver is exactly the hazard JX004 exists
-# to catch.
-_HOST_OPS = ("infeed", "outfeed", "outside_call")
+# jitted computation. ``debug_print`` (what ``jax.debug.print`` stages) and
+# ``debug_callback`` are deliberately included: a forgotten
+# jax.debug.print in a driver is exactly the hazard JX004 exists to catch.
+_HOST_OPS = ("infeed", "outfeed", "outside_call", "debug_print")
 
 
 def _is_host_op(prim_name: str) -> bool:

@@ -138,6 +138,10 @@ def _check_record(rec: PallasCallRecord, case_name: str,
                 for i, (spec, (shape, dt))
                 in enumerate(zip(rec.out_specs, rec.out_shapes))])
 
+    # whole-array operands outside the blocked VMEM pipeline (SMEM
+    # scalars, HBM refs a kernel addresses by DMA) have no block to check
+    roles = [r for r in roles if r[2].block_shape is not None]
+
     # PK001: exact tiling, and PK003: per-step block bytes
     step_bytes = 0
     for role, i, spec, shape, dt in roles:
@@ -152,8 +156,8 @@ def _check_record(rec: PallasCallRecord, case_name: str,
             continue
         elems = 1
         for d, (b, s) in enumerate(zip(block, shape)):
-            if b is None:
-                b = s
+            if b is None:  # a squeezed dim: one row per block
+                b = 1
             elems *= b
             if b <= 0 or s % b != 0:
                 findings.append(Finding(
@@ -189,8 +193,7 @@ def _check_record(rec: PallasCallRecord, case_name: str,
 
     writers: dict[int, dict[tuple, int]] = {}
     for role, i, spec, shape, _dt in roles:
-        block = tuple(b if b is not None else s
-                      for b, s in zip(spec.block_shape, shape))
+        block = tuple(1 if b is None else b for b in spec.block_shape)
         if len(block) != len(shape):
             continue  # already a PK001 rank error
         seen_oob = False

@@ -129,7 +129,8 @@ class BenchContext:
     def mesh(self, nodes: Optional[int] = None):
         nodes = nodes or self.nodes
         if nodes not in self._meshes:
-            self._meshes[nodes] = jax.make_mesh((nodes,), ("data",))
+            from repro.launch.mesh import make_mesh
+            self._meshes[nodes] = make_mesh((nodes,), ("data",))
         return self._meshes[nodes]
 
     def log(self, scale: Scale, nodes: Optional[int] = None,
@@ -392,22 +393,19 @@ def _run_kernel(scale: Scale, ctx: BenchContext, *, kernel: str,
     from repro.kernels.windowed_ratio.ref import windowed_ratio_ref
 
     args = _kernel_inputs(scale, kernel)
-    interpret = jax.default_backend() != "tpu"
     if kernel == "segment_hist":
         work = scale.records_per_node
-        fn = (jax.jit(lambda *a: segment_hist(
-                  *a, num_sites=scale.num_sites, interpret=interpret))
+        fn = (jax.jit(lambda *a: segment_hist(*a, num_sites=scale.num_sites))
               if path == "pallas" else
               jax.jit(lambda *a: segment_hist_ref(
                   *a, num_sites=scale.num_sites, num_weeks=52)))
     elif kernel == "windowed_ratio":
         work = scale.num_sites
-        fn = (jax.jit(lambda h: windowed_ratio(h, interpret=interpret))
+        fn = (jax.jit(windowed_ratio)
               if path == "pallas" else jax.jit(windowed_ratio_ref))
     else:  # powerlaw_sample
         work = scale.records_per_node
-        fn = (jax.jit(lambda u, c: powerlaw_sample(
-                  u, c, interpret=interpret))
+        fn = (jax.jit(powerlaw_sample)
               if path == "pallas" else jax.jit(powerlaw_sample_ref))
     timing, _ = time_callable(fn, *args, warmup=scale.warmup,
                               iters=scale.iters)
@@ -755,7 +753,9 @@ def _run_multiproc(scale: Scale, ctx: BenchContext, *,
 
     from repro.bench import schema
     from repro.bench.timing import timing_from_samples
+    from repro.common.env import refuse_gang_off_cpu
 
+    refuse_gang_off_cpu(f"sweep_multiproc_p{procs}")
     src_root = str(pathlib.Path(__file__).resolve().parents[2])
     chunks = max(1, scale.records_per_node // scale.chunk_records)
     sub_env = dict(os.environ)

@@ -30,6 +30,7 @@ import time  # noqa: E402
 import jax  # noqa: E402
 
 from repro.bench import registry, schema  # noqa: E402
+from repro.common.env import enable_compile_cache  # noqa: E402
 
 
 def _csv_row(entry: dict) -> str:
@@ -87,6 +88,7 @@ def main(argv=None) -> int:
                     help="list scenario names (with the preset's selection "
                          "marked) and exit")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     selected = set(registry.preset_scenario_names(args.preset))
     if args.list:

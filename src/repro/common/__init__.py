@@ -1,5 +1,5 @@
-"""Shared substrate: array types, pytree helpers, numerics config, jax
-version shims."""
+"""Shared substrate: array types, pytree helpers, numerics config, the
+sharding entry points."""
 
 from repro.common.compat import shard_map
 from repro.common.types import (

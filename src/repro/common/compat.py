@@ -1,47 +1,13 @@
-"""Version compatibility shims for the installed jax.
+"""The sharding entry points every module imports from one place.
 
-``shard_map`` moved twice across jax releases:
-
-- old:  ``jax.experimental.shard_map.shard_map(f, mesh, in_specs, out_specs,
-        check_rep=...)``
-- new:  ``jax.shard_map(f, mesh=..., in_specs=..., out_specs=...,
-        check_vma=...)`` (``check_rep`` was renamed ``check_vma`` when the
-        replication checker became the varying-manual-axes checker)
-
-Every module in this repo imports ``shard_map`` from here and uses the *new*
-keyword spelling (``check_vma``); the shim translates for older jax.
+``shard_map`` (with the ``check_vma`` keyword) and ``axis_size`` are the
+installed jax's own (``jax.shard_map``, ``jax.lax.axis_size``); keeping one
+import site means an upstream move is a one-line change here.
 """
 
 from __future__ import annotations
 
-try:  # jax >= 0.6: top-level export with check_vma
-    from jax import shard_map as _shard_map
+from jax import shard_map
+from jax.lax import axis_size
 
-    _CHECK_KW = "check_vma"
-except ImportError:  # older jax: experimental module with check_rep
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _CHECK_KW = "check_rep"
-
-
-def axis_size(axis_name) -> int:
-    """``jax.lax.axis_size`` with a fallback for jax versions predating it.
-
-    ``psum`` of a concrete Python scalar short-circuits to ``value * size``
-    during tracing, so the fallback still yields a static int usable in
-    shape arithmetic inside ``shard_map``.
-    """
-    import jax
-
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None, **kwargs):
-    """``jax.shard_map`` with the ``check_vma``/``check_rep`` rename papered
-    over. Accepts the new-style ``check_vma`` keyword on any jax."""
-    if check_vma is not None:
-        kwargs[_CHECK_KW] = check_vma
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kwargs)
+__all__ = ["axis_size", "shard_map"]

@@ -7,13 +7,14 @@ their own ``--xla_force_host_platform_device_count`` string surgery. They all
 route through here now, so device forcing, latency-hiding flags, and the
 cross-process collective configuration have exactly one implementation.
 
-Everything in this module except :func:`enable_cpu_collectives` is
-**jax-import-free**: XLA reads ``XLA_FLAGS`` once, when the backend first
-initializes (the first device query — NOT ``import jax``), so these helpers
-must run before that. They refuse — returning ``False`` — once a backend is
-already up, rather than silently setting flags that will never be read.
+Everything in this module except :func:`enable_cpu_collectives` and
+:func:`enable_compile_cache` is **jax-import-free**: XLA reads
+``XLA_FLAGS`` once, when the backend first initializes (the first device
+query — NOT ``import jax``), so these helpers must run before that. They
+refuse — returning ``False`` — once a backend is already up, rather than
+silently setting flags that will never be read.
 (Merely having ``import jax`` executed is fine: importing this module pulls
-in ``repro.common``, whose compat shims import jax, so the old
+in ``repro.common``, whose ``compat`` module imports jax, so the old
 ``"jax" in sys.modules`` test would always trip.)
 
 Flag hygiene: XLA aborts at startup on an *unknown* flag, so
@@ -26,10 +27,13 @@ safe latency-hiding flag — overlap there comes from async dispatch (see
 from __future__ import annotations
 
 import os
+import pathlib
 import sys
 from typing import Optional, Sequence
 
 FORCE_DEVICES_FLAG = "--xla_force_host_platform_device_count"
+COMPILE_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+REPO_ROOT = pathlib.Path(__file__).resolve().parents[3]
 
 # Known-good latency-hiding / async-collective flags per platform. Unknown
 # XLA flags are *fatal* at startup, so nothing speculative goes in here.
@@ -183,3 +187,39 @@ def enable_cpu_collectives(impl: str = "gloo") -> bool:
     except (AttributeError, ValueError):
         return False
     return True
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for an entry point and
+    return its directory.
+
+    ``$JAX_COMPILATION_CACHE_DIR`` wins when it is set; otherwise the cache
+    lives at the fixed ``<repo>/.jax_cache`` (git-ignored). The directory
+    is never built from temporary names, process ids or the time: a cache
+    whose path moves never hits. Only entry points (the launchers,
+    ``repro.bench.run``, ``chip_smoke.py``) call this — importing the
+    library sets no cache.
+    """
+    import jax
+
+    path = os.environ.get(COMPILE_CACHE_ENV) or str(REPO_ROOT / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def refuse_gang_off_cpu(what: str) -> None:
+    """Refuse to fork JAX worker processes unless the platform is forced
+    to ``cpu``.
+
+    A multi-process gang on one host is the CPU rehearsal of a multi-host
+    run. On a host with a chip, the first process that touches JAX holds
+    the chips and every other one fails or hangs, so the chips are driven
+    from ONE process through one mesh over all local devices.
+    """
+    if os.environ.get("JAX_PLATFORMS", "").strip().lower() != "cpu":
+        raise RuntimeError(
+            f"{what} forks one JAX process per rank, which only works as a "
+            f"CPU rehearsal: set JAX_PLATFORMS=cpu. On a host with chips, "
+            f"one process drives every local chip through one mesh "
+            f"(repro.launch.malstone --nodes N without --num-processes; "
+            f"chip_smoke.py --four-chips)")

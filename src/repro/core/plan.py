@@ -8,10 +8,7 @@ they are actually selected.
 
 from __future__ import annotations
 
-import functools
 from typing import Optional
-
-import jax
 
 from repro.common.types import ExchangePlan
 
@@ -37,15 +34,13 @@ def resolve_histogram_fns(plan: ExchangePlan, histogram_fn=None):
             segment_hist_eventlog,
             segment_hist_packed_words,
         )
-        interpret = jax.default_backend() != "tpu"
 
         def word_fn(words, my_index, s_local, num_weeks, p):
             return segment_hist_packed_words(
                 words, my_index, num_sites_local=s_local, num_partitions=p,
-                num_weeks=num_weeks, interpret=interpret)
+                num_weeks=num_weeks)
 
-        return (functools.partial(segment_hist_eventlog, interpret=interpret),
-                word_fn)
+        return segment_hist_eventlog, word_fn
     return None, None
 
 

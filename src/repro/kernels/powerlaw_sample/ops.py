@@ -3,10 +3,12 @@
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import resolve_interpret
 from repro.kernels.powerlaw_sample.powerlaw_sample import (
     CDF_TILE,
     RECORD_TILE,
@@ -23,7 +25,7 @@ def _round_up(x: int, m: int) -> int:
 def powerlaw_sample(u: jnp.ndarray, cdf: jnp.ndarray, *,
                     record_tile: int = RECORD_TILE,
                     cdf_tile: int = CDF_TILE,
-                    interpret: bool = True) -> jnp.ndarray:
+                    interpret: Optional[bool] = None) -> jnp.ndarray:
     """Inverse-CDF sampling: int32 site indices, same leading shape as ``u``.
 
     ``cdf`` must be the inclusive normalized cumulative weights (sorted
@@ -35,15 +37,15 @@ def powerlaw_sample(u: jnp.ndarray, cdf: jnp.ndarray, *,
     s_pad = _round_up(max(s, 1), cdf_tile)
 
     u_p = jnp.pad(u.astype(jnp.float32), (0, n_pad - n))
-    u_p = u_p.reshape(n_pad // record_tile, record_tile)
+    u_p = u_p.reshape(n_pad // record_tile, 1, record_tile)
     # pad with +2.0: strictly greater than any u, never counted
     cdf_p = jnp.pad(cdf.astype(jnp.float32), (0, s_pad - s),
                     constant_values=2.0)
-    cdf_p = cdf_p.reshape(s_pad // cdf_tile, cdf_tile)
+    cdf_p = cdf_p.reshape(s_pad, 1)
 
     counts = powerlaw_sample_pallas(
-        u_p, cdf_p, num_sites=s, record_tile=record_tile, cdf_tile=cdf_tile,
-        interpret=interpret)
+        u_p, cdf_p, record_tile=record_tile, cdf_tile=cdf_tile,
+        interpret=resolve_interpret(interpret))
     idx = counts.reshape(-1)[:n]
     return jnp.clip(idx, 0, s - 1).astype(jnp.int32)
 

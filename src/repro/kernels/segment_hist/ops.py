@@ -1,19 +1,22 @@
 """Public jit'd wrapper for the segment_hist Pallas kernel.
 
 Handles padding (records to a tile multiple, sites to the site-tile
-multiple), the [S, 2*W_pad] -> [S, W, 2] relayout, and the interpret-mode
-switch (CPU container validates the kernel body in interpret mode; on TPU
-pass ``interpret=False``).
+multiple), the ``[n_tiles, 1, record_tile]`` record layout, the
+[S, 2*W_pad] -> [S, W, 2] relayout, and the interpret-mode switch
+(``repro.kernels.resolve_interpret``: compiled on TPU, interpreted
+elsewhere unless a caller forces it).
 """
 
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
 from repro.common.types import EventLog, WEEKS_PER_YEAR
+from repro.kernels import resolve_interpret
 from repro.kernels.segment_hist.segment_hist import (
     RECORD_TILE,
     SITE_TILE,
@@ -32,7 +35,7 @@ def segment_hist(site: jnp.ndarray, week: jnp.ndarray, mark: jnp.ndarray,
                  num_weeks: int = WEEKS_PER_YEAR,
                  site_tile: int = SITE_TILE,
                  record_tile: int = RECORD_TILE,
-                 interpret: bool = True) -> jnp.ndarray:
+                 interpret: Optional[bool] = None) -> jnp.ndarray:
     """int32 [num_sites, num_weeks, 2] histogram via the Pallas kernel."""
     n = site.shape[0]
     n_pad = _round_up(max(n, 1), record_tile)
@@ -42,14 +45,15 @@ def segment_hist(site: jnp.ndarray, week: jnp.ndarray, mark: jnp.ndarray,
     def prep(x, fill=0):
         x = x.astype(jnp.int32).reshape(-1)
         x = jnp.pad(x, (0, n_pad - n), constant_values=fill)
-        return x.reshape(n_pad // record_tile, record_tile)
+        return x.reshape(n_pad // record_tile, 1, record_tile)
 
     ok = (valid.astype(jnp.int32) > 0) & (site >= 0) & (site < num_sites) \
         & (week >= 0) & (week < num_weeks)
     out = segment_hist_pallas(
         prep(site), prep(week), prep(mark), prep(ok.astype(jnp.int32)),
         num_sites_padded=s_pad, num_weeks=num_weeks,
-        site_tile=site_tile, record_tile=record_tile, interpret=interpret)
+        site_tile=site_tile, record_tile=record_tile,
+        interpret=resolve_interpret(interpret))
 
     total = out[:num_sites, :num_weeks]
     marked = out[:num_sites, w_pad:w_pad + num_weeks]
@@ -59,7 +63,7 @@ def segment_hist(site: jnp.ndarray, week: jnp.ndarray, mark: jnp.ndarray,
 def segment_hist_eventlog(log: EventLog, num_sites: int,
                           num_weeks: int = WEEKS_PER_YEAR,
                           site_offset: int = 0,
-                          interpret: bool = True) -> jnp.ndarray:
+                          interpret: Optional[bool] = None) -> jnp.ndarray:
     """Drop-in replacement for ``repro.core.spm.site_week_histogram`` backed
     by the Pallas kernel (same signature contract as ``histogram_fn`` in the
     backends)."""
@@ -78,7 +82,8 @@ def segment_hist_packed_words(words: jnp.ndarray, my_index: jnp.ndarray, *,
                               num_weeks: int = WEEKS_PER_YEAR,
                               site_tile: int = SITE_TILE,
                               record_tile: int = RECORD_TILE,
-                              interpret: bool = True) -> jnp.ndarray:
+                              interpret: Optional[bool] = None
+                              ) -> jnp.ndarray:
     """The MapReduce reducer's fused unpack+histogram over packed words.
 
     ``words`` is the flat uint32 stream the exchange delivered (invalid
@@ -95,13 +100,13 @@ def segment_hist_packed_words(words: jnp.ndarray, my_index: jnp.ndarray, *,
 
     words_t = jax.lax.bitcast_convert_type(
         jnp.pad(words.reshape(-1), (0, n_pad - n)), jnp.int32
-    ).reshape(n_pad // record_tile, record_tile)
+    ).reshape(n_pad // record_tile, 1, record_tile)
     my = jnp.asarray(my_index, jnp.int32).reshape(1, 1)
 
     out = segment_hist_packed_pallas(
         words_t, my, num_sites_padded=s_pad, num_weeks=num_weeks,
         num_partitions=num_partitions, site_tile=site_tile,
-        record_tile=record_tile, interpret=interpret)
+        record_tile=record_tile, interpret=resolve_interpret(interpret))
 
     total = out[:num_sites_local, :num_weeks]
     marked = out[:num_sites_local, w_pad:w_pad + num_weeks]

@@ -3,10 +3,12 @@
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import resolve_interpret
 from repro.kernels.windowed_ratio.windowed_ratio import (
     SITE_TILE,
     masked_window_ratio_pallas,
@@ -20,7 +22,7 @@ def _round_up(x: int, m: int) -> int:
 
 @functools.partial(jax.jit, static_argnames=("site_tile", "interpret"))
 def windowed_ratio(hist: jnp.ndarray, *, site_tile: int = SITE_TILE,
-                   interpret: bool = True):
+                   interpret: Optional[bool] = None):
     """MalStone B finalize: hist int32 [S, W, 2] ->
     (rho f32 [S, W], cum_total i32, cum_marked i32)."""
     s, w, _ = hist.shape
@@ -32,14 +34,15 @@ def windowed_ratio(hist: jnp.ndarray, *, site_tile: int = SITE_TILE,
 
     rho, cum_t, cum_m = windowed_ratio_pallas(
         pad(hist[..., 0]), pad(hist[..., 1]),
-        site_tile=site_tile, interpret=interpret)
+        site_tile=site_tile, interpret=resolve_interpret(interpret))
     return rho[:s, :w], cum_t[:s, :w], cum_m[:s, :w]
 
 
 @functools.partial(jax.jit, static_argnames=("site_tile", "interpret"))
 def masked_window_ratio(hist: jnp.ndarray, num_masks: jnp.ndarray,
                         den_masks: jnp.ndarray, *,
-                        site_tile: int = SITE_TILE, interpret: bool = True):
+                        site_tile: int = SITE_TILE,
+                        interpret: Optional[bool] = None):
     """Batched query reducer for the serving engine: hist int32 [S, W, 2]
     plus N numerator/denominator week masks (bool/int [N, W]) ->
     (rho f32 [N, S], num i32 [N, S], den i32 [N, S]) in one kernel
@@ -64,7 +67,7 @@ def masked_window_ratio(hist: jnp.ndarray, num_masks: jnp.ndarray,
     rho, num, den = masked_window_ratio_pallas(
         pad_hist(hist[..., 0]), pad_hist(hist[..., 1]),
         pad_mask(num_masks), pad_mask(den_masks),
-        site_tile=site_tile, interpret=interpret)
+        site_tile=site_tile, interpret=resolve_interpret(interpret))
     return rho[:n, :s], num[:n, :s], den[:n, :s]
 
 

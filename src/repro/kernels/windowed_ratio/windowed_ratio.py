@@ -13,6 +13,8 @@ matrix, so even the scan maps onto the MXU:
     cum[TS, W] = hist[TS, W] @ L^T,   L[t, w] = 1{w <= t}
 
 (W = 52 -> one 64/128-padded matmul; exact in f32 since counts < 2^24.)
+Counts exceed bf16's 8-bit mantissa, so every matmul asks for full f32
+contraction (``Precision.HIGHEST``) rather than the MXU's default pass.
 """
 
 from __future__ import annotations
@@ -24,6 +26,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 SITE_TILE = 512
+_EXACT = jax.lax.Precision.HIGHEST
 
 
 def _kernel(total_ref, marked_ref, rho_ref, cum_total_ref, cum_marked_ref, *,
@@ -37,10 +40,10 @@ def _kernel(total_ref, marked_ref, rho_ref, cum_total_ref, cum_marked_ref, *,
     tri = jnp.where(row <= col, 1.0, 0.0).astype(jnp.float32)
 
     cum_total = jax.lax.dot_general(
-        total, tri, (((1,), (0,)), ((), ())),
+        total, tri, (((1,), (0,)), ((), ())), precision=_EXACT,
         preferred_element_type=jnp.float32)
     cum_marked = jax.lax.dot_general(
-        marked, tri, (((1,), (0,)), ((), ())),
+        marked, tri, (((1,), (0,)), ((), ())), precision=_EXACT,
         preferred_element_type=jnp.float32)
 
     rho = jnp.where(cum_total > 0.0,
@@ -62,10 +65,10 @@ def _masked_kernel(total_ref, marked_ref, nmask_ref, dmask_ref,
     dmask = dmask_ref[...]
 
     num = jax.lax.dot_general(
-        nmask, marked, (((1,), (1,)), ((), ())),
+        nmask, marked, (((1,), (1,)), ((), ())), precision=_EXACT,
         preferred_element_type=jnp.float32)       # [N_pad, TS]
     den = jax.lax.dot_general(
-        dmask, total, (((1,), (1,)), ((), ())),
+        dmask, total, (((1,), (1,)), ((), ())), precision=_EXACT,
         preferred_element_type=jnp.float32)
 
     rho = jnp.where(den > 0.0, num / jnp.maximum(den, 1.0), 0.0)
@@ -78,7 +81,7 @@ def masked_window_ratio_pallas(total: jnp.ndarray, marked: jnp.ndarray,
                                num_masks: jnp.ndarray,
                                den_masks: jnp.ndarray,
                                *, site_tile: int = SITE_TILE,
-                               interpret: bool = False):
+                               interpret: bool):
     """Raw entry: total/marked int32 [S_pad, W_pad]; num/den masks f32
     [N_pad, W_pad] (N_pad a sublane multiple). Returns
     (rho f32, num i32, den i32), all [N_pad, S_pad] — one row per query,
@@ -110,7 +113,7 @@ def masked_window_ratio_pallas(total: jnp.ndarray, marked: jnp.ndarray,
 
 def windowed_ratio_pallas(total: jnp.ndarray, marked: jnp.ndarray,
                           *, site_tile: int = SITE_TILE,
-                          interpret: bool = False):
+                          interpret: bool):
     """Raw entry: total/marked int32 [S_pad, W_pad]; S_pad % site_tile == 0,
     W_pad a lane multiple. Returns (rho f32, cum_total i32, cum_marked i32),
     all [S_pad, W_pad]."""

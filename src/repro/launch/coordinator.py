@@ -33,7 +33,6 @@ points call ``preparse()`` / ``initialize()`` at module import time, before
 from __future__ import annotations
 
 import dataclasses
-import os
 import socket
 import subprocess
 import sys
@@ -110,7 +109,10 @@ def spawn_local(cfg: DistConfig, argv: Optional[Sequence[str]] = None, *,
     already mentions one). Workers inherit the environment and stream their
     output directly; returns the first nonzero worker exit status (0 when
     all succeed). On timeout every worker is killed and 124 is returned.
+    Refuses (``RuntimeError``) unless ``JAX_PLATFORMS=cpu``: one process
+    per chip (``env.refuse_gang_off_cpu``).
     """
+    env.refuse_gang_off_cpu("spawn_local")
     argv = list(sys.argv if argv is None else argv)
     address = cfg.coordinator or f"127.0.0.1:{pick_port()}"
     procs = []

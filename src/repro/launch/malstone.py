@@ -33,7 +33,7 @@ single-device host oracle.
 
 import argparse
 
-from repro.common.env import preparse_nodes
+from repro.common.env import enable_compile_cache, preparse_nodes
 from repro.launch import coordinator
 
 _N = preparse_nodes(default=1)
@@ -53,6 +53,7 @@ from repro.common.types import ExchangePlan
 from repro.core import run as malstone
 from repro.launch.mesh import (
     make_global_mesh,
+    make_mesh,
     replicate_to_mesh,
     shard_log_to_mesh,
 )
@@ -150,6 +151,7 @@ def main():
                          "(schema: repro/bench/schema.py) for "
                          "repro.bench.compare")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if _DIST.is_distributed:
         if jax.device_count() != args.nodes:
@@ -161,7 +163,7 @@ def main():
               f"{jax.local_device_count()} local of "
               f"{jax.device_count()} global devices", flush=True)
     else:
-        mesh = jax.make_mesh((args.nodes,), ("data",))
+        mesh = make_mesh((args.nodes,), ("data",))
     cfg = MalGenConfig(num_sites=args.sites, num_entities=args.entities)
     total = args.nodes * args.records_per_node
 
@@ -181,9 +183,9 @@ def main():
                         max_shuffle_rounds=args.max_shuffle_rounds,
                         histogram_impl=args.histogram_impl)
     if args.histogram_impl == "pallas":
+        from repro.kernels import resolve_interpret
         print("histogram: Pallas segment_hist kernel"
-              + (" (interpret mode)" if jax.default_backend() != "tpu"
-                 else ""))
+              + (" (interpret mode)" if resolve_interpret() else ""))
 
     if args.stream_chunks:
         if args.records_per_node % args.stream_chunks:

@@ -1,9 +1,15 @@
-"""Production mesh definitions.
+"""Mesh construction: the repo's one mesh constructor and its layouts.
 
-A FUNCTION (not a module-level constant) so importing this module never
+Functions (not module-level constants) so importing this module never
 touches jax device state — device count is locked at first jax init, and
 only launch/dryrun.py (which sets XLA_FLAGS before any import) should see
 512 devices.
+
+Every mesh is built by :func:`make_mesh` with ``AxisType.Auto`` axes.
+``jax.make_mesh`` defaults to Explicit axes on current jax; arrays then
+carry their mesh axes in their types, and the Pallas lowering of the
+serving query kernel rejects them (``ShardingTypeError``). The drivers'
+``shard_map`` programs are written for Auto axes.
 """
 
 from __future__ import annotations
@@ -12,6 +18,17 @@ import math
 
 import jax
 import numpy as np
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes, *, devices=None):
+    """``jax.make_mesh`` with every axis ``AxisType.Auto`` — the only place
+    in ``src/`` that calls it. ``devices`` defaults to ``jax.devices()``
+    (pass described devices to compile for a chip that is not attached)."""
+    axes = tuple(axes)
+    return jax.make_mesh(tuple(shape), axes,
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -19,7 +36,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     Multi-pod:  (2, 16, 16) = (pod, data, model), 512 chips."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def batch_axes(mesh) -> tuple:
@@ -44,7 +61,7 @@ def make_host_mesh(n: int = 8, axes=("data",), shape=None):
         raise ValueError(
             f"mesh shape {shape} places {math.prod(shape)} devices,"
             f" not n={n}")
-    return jax.make_mesh(shape, tuple(axes))
+    return make_mesh(shape, axes)
 
 
 def make_global_mesh(axes=("data",)):
@@ -52,7 +69,7 @@ def make_global_mesh(axes=("data",)):
     ``jax.distributed.initialize`` has run (``jax.devices()`` is the global
     device list), degenerating to the usual host mesh single-process.
 
-    Built from the explicit device array (not ``jax.make_mesh``'s
+    Built from the explicit device array (not :func:`make_mesh`'s
     reordering heuristics) so the device→rank order is the deterministic
     process-major one the multi-process bit-identity tests assume. Note
     ``np.array``, never ``jnp.array``: Device objects aren't JAX types.

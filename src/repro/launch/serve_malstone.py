@@ -45,7 +45,9 @@ import numpy as np  # noqa: E402
 
 from repro.bench import schema  # noqa: E402
 from repro.bench.timing import time_callable, timing_from_samples  # noqa: E402
+from repro.common.env import enable_compile_cache  # noqa: E402
 from repro.common.types import ExchangePlan  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.malgen import MalGenConfig, make_seed_streaming  # noqa: E402
 from repro.serve import (  # noqa: E402
     MalStoneService,
@@ -149,12 +151,13 @@ def main(argv=None) -> int:
                     help="also write the three phases as a BENCH_*.json"
                          " document (repro/bench/schema.py)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.ingest_chunks < 1:
         ap.error("--ingest-chunks must be >= 1")
     if args.query_batches < 1:
         ap.error("--query-batches must be >= 1")
 
-    mesh = jax.make_mesh((args.nodes,), ("data",))
+    mesh = make_mesh((args.nodes,), ("data",))
     cfg = MalGenConfig(num_sites=args.sites, num_entities=args.entities)
     num_chunks = args.nodes * args.ingest_chunks
     total = num_chunks * args.chunk_records

@@ -139,8 +139,9 @@ class MalStoneService:
     ``seed`` / ``cfg`` / ``num_chunks`` enable seed-mode ingest
     (``ingest_chunks``); log-mode ``ingest`` is always available.
     ``kernel_path`` selects the query reducer: ``"pallas"`` (the masked
-    ``windowed_ratio`` kernel; interpreted off-TPU), ``"ref"`` (jnp
-    einsum), or ``"auto"`` (pallas, interpret chosen by backend).
+    ``windowed_ratio`` kernel; compiled on TPU, interpreted elsewhere —
+    ``repro.kernels.resolve_interpret``; ``interpret`` forces either),
+    ``"ref"`` (jnp einsum), or ``"auto"`` (pallas).
     """
 
     def __init__(self, *,
@@ -183,8 +184,7 @@ class MalStoneService:
                 f"unknown kernel_path {kernel_path!r}; "
                 f"have ('auto', 'pallas', 'ref')")
         self.kernel_path = kernel_path
-        self.interpret = (jax.default_backend() != "tpu"
-                          if interpret is None else interpret)
+        self.interpret = interpret
 
         # seed-mode configuration (optional)
         self.seed, self.cfg = seed, cfg

@@ -145,7 +145,7 @@ def encode_query_batch(specs: Sequence[QuerySpec],
 def batched_query(hist: jnp.ndarray, num_masks: jnp.ndarray,
                   den_masks: jnp.ndarray, sites: jnp.ndarray, *,
                   max_top_k: int = 0, kernel_path: str = "pallas",
-                  interpret: bool = True):
+                  interpret: Optional[bool] = None):
     """ONE device dispatch answering every stacked query.
 
     hist int32 [S, W, 2] (the resident snapshot), masks bool [N, W],

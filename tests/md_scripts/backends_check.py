@@ -18,12 +18,13 @@ from repro.core import (
     malstone_single_device,
     pad_log_to,
 )
+from repro.launch.mesh import make_mesh
 from repro.malgen import MalGenConfig, generate_sharded_log
 
 
 def main():
     assert jax.device_count() == 8, jax.devices()
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,), ("data",))
 
     cfg = MalGenConfig(num_sites=301, num_entities=1000,
                        marked_site_fraction=0.2, marked_event_fraction=0.3)

@@ -28,6 +28,7 @@ from repro.core import (
     malstone_single_device,
     run,
 )
+from repro.launch.mesh import make_mesh
 from repro.malgen import MalGenConfig, generate_sharded_log
 
 STAT_FIELDS = ("sent", "overflow", "capacity", "rounds", "residual",
@@ -43,7 +44,7 @@ def assert_exact(got, ref, msg):
 
 def main():
     assert jax.device_count() == 8, jax.devices()
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,), ("data",))
 
     cfg = MalGenConfig(num_sites=301, num_entities=1000,
                        marked_site_fraction=0.2, marked_event_fraction=0.3)

@@ -31,6 +31,7 @@ from repro.core import (
     malstone_run_generated_streaming,
     malstone_run_streaming,
 )
+from repro.launch.mesh import make_mesh
 from repro.malgen import MalGenConfig, generate_shard_device, generate_sharded_log
 
 BACKENDS = ("streams", "sphere", "mapreduce", "mapreduce_combiner")
@@ -38,7 +39,7 @@ BACKENDS = ("streams", "sphere", "mapreduce", "mapreduce_combiner")
 
 def main():
     assert jax.device_count() == 8, jax.devices()
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,), ("data",))
     parts, rps = 8, 1024
 
     cfg = MalGenConfig(num_sites=301, num_entities=1000,

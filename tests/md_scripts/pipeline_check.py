@@ -9,10 +9,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.distributed import PipelineConfig, pipeline_apply
+from repro.launch.mesh import make_mesh
 
 
 def main():
-    mesh = jax.make_mesh((4,), ("pipe",))
+    mesh = make_mesh((4,), ("pipe",))
     s, m, mb, d = 4, 4, 2, 8
     w = jax.random.normal(jax.random.key(0), (s, d, d)) * 0.3
 

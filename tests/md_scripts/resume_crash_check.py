@@ -32,6 +32,7 @@ import numpy as np
 from repro.core import malstone_run, malstone_run_streaming
 from repro.core.resume import ResumableRunner
 from repro.faults import FaultPlan
+from repro.launch.mesh import make_mesh
 from repro.malgen import MalGenConfig, generate_chunked_log, make_seed_streaming
 
 CFG = MalGenConfig(num_sites=301, num_entities=1000,
@@ -54,7 +55,7 @@ def _save(out_npz, out):
 def main():
     backend, phase, ckpt_dir, out_npz = sys.argv[1:5]
     assert jax.device_count() == 2, jax.devices()
-    mesh = jax.make_mesh((2,), ("data",))
+    mesh = make_mesh((2,), ("data",))
     seed = make_seed_streaming(jax.random.key(13), CFG, NUM_CHUNKS, CHUNK)
     runner = ResumableRunner(
         seed, CFG, mesh=mesh, num_chunks=NUM_CHUNKS, chunk_records=CHUNK,

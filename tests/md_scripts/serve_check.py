@@ -20,6 +20,7 @@ import numpy as np
 
 from repro.core import malstone_run_streaming
 from repro.kernels.windowed_ratio.ref import masked_window_ratio_ref
+from repro.launch.mesh import make_mesh
 from repro.malgen import (
     MalGenConfig,
     generate_chunked_log,
@@ -45,7 +46,7 @@ def check_stats(got, ref, msg):
 
 def main():
     assert jax.device_count() == 8, jax.devices()
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,), ("data",))
 
     cfg = MalGenConfig(num_sites=301, num_entities=1000,
                        marked_site_fraction=0.2, marked_event_fraction=0.3)

@@ -12,6 +12,7 @@ import jax
 import numpy as np
 
 from repro.core import malstone_run, malstone_run_streaming
+from repro.launch.mesh import make_mesh
 from repro.malgen import (
     MalGenConfig,
     generate_chunked_log,
@@ -24,7 +25,7 @@ BACKENDS = ("streams", "sphere", "mapreduce", "mapreduce_combiner")
 
 def main():
     assert jax.device_count() == 8, jax.devices()
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,), ("data",))
 
     cfg = MalGenConfig(num_sites=301, num_entities=1000,
                        marked_site_fraction=0.2, marked_event_fraction=0.3)

@@ -151,7 +151,7 @@ class TestJaxprPasses:
         closed = jax.make_jaxpr(chatty)(jax.ShapeDtypeStruct((4,), jnp.int32))
         found = analyze_jaxpr(closed, "seeded")
         assert [f.rule for f in found] == ["JX004"]
-        assert "debug_callback" in found[0].location
+        assert "debug_print" in found[0].location
 
     def test_concretization_raises_the_type_jx001_catches(self):
         """trace_pass converts jax.errors.JAXTypeError into JX001; prove

@@ -48,6 +48,7 @@ from repro.core.backends.mapreduce import (
 )
 from repro.kernels.count_scatter import count_scatter
 from repro.kernels.count_scatter.ref import count_scatter_ref
+from repro.launch.mesh import make_mesh
 from repro.malgen import (
     MalGenConfig,
     generate_full_log,
@@ -66,7 +67,7 @@ STAT_FIELDS = ("sent", "overflow", "capacity", "rounds", "residual",
 
 @pytest.fixture(scope="module")
 def mesh():
-    return jax.make_mesh((1,), ("data",))
+    return make_mesh((1,), ("data",))
 
 
 @pytest.fixture(scope="module")
@@ -230,7 +231,7 @@ def assert_scatter_equal(got, ref, msg=""):
                                   err_msg=f"starts ({msg})")
 
 
-@settings(max_examples=25)
+@settings(max_examples=25, deadline=None)
 @given(st.integers(1, 12), st.integers(1, 3000), st.integers(0, 10_000))
 def test_ref_is_the_stable_argsort_property(p, n, seed):
     """Property: the jnp oracle == stable argsort + gather + searchsorted

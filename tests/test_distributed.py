@@ -6,6 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.launch.mesh import make_mesh
 from repro.optim.compression import (
     compress_int8,
     decompress_int8,
@@ -55,7 +56,7 @@ class TestShardingRules:
     def test_divisibility_fallback(self):
         from jax.sharding import PartitionSpec as P
         from repro.models.sharding import spec_for
-        mesh = jax.make_mesh((1,), ("data",))
+        mesh = make_mesh((1,), ("data",))
         # dim 7 not divisible by data=1? divisible; use rules with data
         spec = spec_for((8, 7), ("embed", None), {"embed": "data"}, mesh)
         assert spec == P("data")
@@ -64,7 +65,7 @@ class TestShardingRules:
         """The (pod, data) binding must keep data on a pod-less mesh."""
         from jax.sharding import PartitionSpec as P
         from repro.models.sharding import spec_for
-        mesh = jax.make_mesh((1,), ("data",))
+        mesh = make_mesh((1,), ("data",))
         spec = spec_for((4, 4), ("batch", None),
                         {"batch": ("pod", "data")}, mesh)
         assert spec == P("data")
@@ -72,7 +73,7 @@ class TestShardingRules:
     def test_no_axis_reuse_within_tensor(self):
         from jax.sharding import PartitionSpec as P
         from repro.models.sharding import spec_for
-        mesh = jax.make_mesh((1,), ("data",))
+        mesh = make_mesh((1,), ("data",))
         spec = spec_for((4, 4), ("a", "b"),
                         {"a": "data", "b": "data"}, mesh)
         assert spec == P("data")  # second binding blocked (axis used)

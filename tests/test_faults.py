@@ -28,6 +28,7 @@ from repro.faults import (
     TelemetryBuffer,
     TransientWorkerError,
 )
+from repro.launch.mesh import make_mesh
 from repro.malgen import MalGenConfig, make_seed_streaming
 
 CFG = MalGenConfig(num_sites=301, num_entities=1000,
@@ -36,14 +37,15 @@ NUM_CHUNKS, CHUNK = 8, 512
 NUM_HOSTS = 4
 FAST_RETRY = RetryPolicy(max_attempts=4, backoff_s=0.0)
 
-# the hypothesis stand-in replays property bodies without pytest fixtures,
-# so the shared runner + fault-free reference live in a module-level cache
+# hypothesis replays property bodies many times per test and cannot hand
+# them function-scoped fixtures, so the shared runner + fault-free
+# reference live in a module-level cache
 _STATE: dict = {}
 
 
 def _runner_and_ref():
     if not _STATE:
-        mesh = jax.make_mesh((1,), ("data",))
+        mesh = make_mesh((1,), ("data",))
         seed = make_seed_streaming(jax.random.key(7), CFG, NUM_CHUNKS, CHUNK)
         runner = ResumableRunner(
             seed, CFG, mesh=mesh, num_chunks=NUM_CHUNKS, chunk_records=CHUNK,

@@ -30,6 +30,7 @@ from repro.core import (
     malstone_run_streaming,
     pad_log_to,
 )
+from repro.launch.mesh import make_mesh
 from repro.malgen import (
     MalGenConfig,
     chunk_shard_hash,
@@ -150,7 +151,7 @@ def test_property_event_ids_unique_host_and_device(seed_int, num_shards):
 
 @pytest.fixture(scope="module")
 def mesh():
-    return jax.make_mesh((1,), ("data",))
+    return make_mesh((1,), ("data",))
 
 
 @pytest.fixture(scope="module")

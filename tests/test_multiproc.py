@@ -110,6 +110,41 @@ class TestXlaFlags:
         assert env.latency_hiding_flags("no_such_platform") == ()
 
 
+class TestCompileCache:
+    @pytest.fixture(autouse=True)
+    def _restore_cache_dir(self):
+        was = jax.config.jax_compilation_cache_dir
+        yield
+        jax.config.update("jax_compilation_cache_dir", was)
+
+    def test_env_var_wins(self, monkeypatch, tmp_path):
+        monkeypatch.setenv(env.COMPILE_CACHE_ENV, str(tmp_path))
+        assert env.enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == str(tmp_path)
+
+    def test_fixed_repo_path_otherwise(self, monkeypatch):
+        monkeypatch.delenv(env.COMPILE_CACHE_ENV, raising=False)
+        path = env.enable_compile_cache()
+        assert path == str(pathlib.Path(SRC).parent / ".jax_cache")
+        assert env.enable_compile_cache() == path  # no pid, tmp or time
+
+
+class TestOneProcessPerChip:
+    @pytest.mark.parametrize("platforms", [None, "", "tpu", "cpu,tpu"])
+    def test_gang_refused_unless_forced_to_cpu(self, monkeypatch, platforms):
+        if platforms is None:
+            monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+        else:
+            monkeypatch.setenv("JAX_PLATFORMS", platforms)
+        with pytest.raises(RuntimeError, match="one mesh"):
+            coordinator.spawn_local(coordinator.DistConfig(num_processes=2),
+                                    ["-c", "pass"], timeout=60)
+
+    def test_gang_allowed_on_cpu(self, monkeypatch):
+        monkeypatch.setenv("JAX_PLATFORMS", " CPU ")
+        env.refuse_gang_off_cpu("test")  # does not raise
+
+
 # ----------------------------------------------------- repro.launch.coordinator
 class TestDistConfig:
     def test_single_process_default(self):

@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from hypothesis import given, settings, strategies as st
+from hypothesis import example, given, settings, strategies as st
 
 from repro.common.types import (
     PACK_MAX_SITES,
@@ -39,6 +39,7 @@ from repro.core.backends.mapreduce import (
     packed_shuffle_supported,
     resolve_packed_shuffle,
 )
+from repro.launch.mesh import make_mesh
 from repro.malgen import MalGenConfig, generate_full_log
 
 CFG = MalGenConfig(num_sites=257, num_entities=700,
@@ -48,7 +49,7 @@ N, CHUNK = 2048, 512
 
 @pytest.fixture(scope="module")
 def mesh():
-    return jax.make_mesh((1,), ("data",))
+    return make_mesh((1,), ("data",))
 
 
 @pytest.fixture(scope="module")
@@ -67,14 +68,16 @@ def assert_exact(got, ref, msg=""):
 
 
 # ------------------------------------------------------- word round-trip
-@settings(max_examples=50)
+@settings(max_examples=50, deadline=None)
 @given(st.integers(0, PACK_MAX_SITES - 1),
        st.integers(0, PACK_MAX_WEEKS - 1),
        st.integers(0, 1))
+@example(0, 0, 0)
+@example(PACK_MAX_SITES - 1, PACK_MAX_WEEKS - 1, 1)
 def test_pack_roundtrip_full_field_ranges(site, week, mark):
     """Property: every representable (site, week, mark) survives the word
-    round-trip, endpoints included (the hypothesis stand-in always replays
-    the field-range endpoints — site = 2^24 - 1, week = 63)."""
+    round-trip, endpoints included (the explicit examples pin the
+    field-range endpoints — site = 2^24 - 1, week = 63)."""
     word = pack_site_week_mark(jnp.int32(site), jnp.int32(week),
                                jnp.int32(mark), jnp.bool_(True))
     s, w, m, v = unpack_site_week_mark(word)

@@ -26,6 +26,7 @@ import pytest
 from repro.core import malstone_run_streaming
 from repro.core.resume import ResumableRunner
 from repro.faults import FaultPlan, SimulatedKill
+from repro.launch.mesh import make_mesh
 from repro.malgen import MalGenConfig, make_seed_streaming
 
 HERE = pathlib.Path(__file__).parent
@@ -40,7 +41,7 @@ NUM_CHUNKS, CHUNK = 8, 512
 
 @pytest.fixture(scope="module")
 def mesh():
-    return jax.make_mesh((1,), ("data",))
+    return make_mesh((1,), ("data",))
 
 
 @pytest.fixture(scope="module")

@@ -31,6 +31,7 @@ from repro.core.spm import (
     malstone_b,
     malstone_b_fixed_denominator,
 )
+from repro.launch.mesh import make_mesh
 from repro.malgen import (
     MalGenConfig,
     generate_chunked_log,
@@ -58,7 +59,7 @@ NUM_CHUNKS, CHUNK = 8, 512
 
 @pytest.fixture(scope="module")
 def mesh():
-    return jax.make_mesh((1,), ("data",))
+    return make_mesh((1,), ("data",))
 
 
 @pytest.fixture(scope="module")

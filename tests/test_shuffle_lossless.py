@@ -36,6 +36,7 @@ from repro.core import (
     pad_log_to,
 )
 from repro.core.streaming import streaming_histogram_from_log
+from repro.launch.mesh import make_mesh
 from repro.malgen import MalGenConfig, generate_full_log
 
 BACKENDS = ("streams", "sphere", "mapreduce", "mapreduce_combiner")
@@ -48,7 +49,7 @@ N, CHUNK = 2048, 512
 
 @pytest.fixture(scope="module")
 def mesh():
-    return jax.make_mesh((1,), ("data",))
+    return make_mesh((1,), ("data",))
 
 
 @pytest.fixture(scope="module")

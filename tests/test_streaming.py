@@ -18,6 +18,7 @@ import numpy as np
 import pytest
 
 from repro.core import malstone_run, malstone_run_streaming
+from repro.launch.mesh import make_mesh
 from repro.malgen import (
     MalGenConfig,
     chunk_marked_records,
@@ -39,7 +40,7 @@ NUM_CHUNKS, CHUNK = 8, 512
 
 @pytest.fixture(scope="module")
 def mesh():
-    return jax.make_mesh((1,), ("data",))
+    return make_mesh((1,), ("data",))
 
 
 @pytest.fixture(scope="module")
