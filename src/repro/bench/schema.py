@@ -1,9 +1,8 @@
 """The stable ``BENCH_<name>.json`` result schema: writer, loader, validator.
 
 Every producer (``repro.bench.run``, ``benchmarks/run.py``,
-``repro.launch.malstone --bench-json``, ``benchmarks/roofline.py
---bench-json``) emits the same document shape so ``repro.bench.compare``
-can diff any two runs:
+``repro.launch.malstone --bench-json``) emits the same document shape so
+``repro.bench.compare`` can diff any two runs:
 
     {
       "schema_version": 1,

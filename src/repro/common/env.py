@@ -199,11 +199,19 @@ def enable_compile_cache() -> str:
     whose path moves never hits. Only entry points (the launchers,
     ``repro.bench.run``, ``chip_smoke.py``) call this — importing the
     library sets no cache.
+
+    The cache key includes each instruction's metadata: the job names its
+    layers with ``jax.named_scope``, and an executable loaded under a key
+    without them would carry another program's op paths into a profile.
+    Source files enter that metadata by base name, so the key does not
+    depend on where the checkout lives.
     """
     import jax
 
     path = os.environ.get(COMPILE_CACHE_ENV) or str(REPO_ROOT / ".jax_cache")
     jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    jax.config.update("jax_hlo_source_file_canonicalization_regex", ".*/")
     return path
 
 

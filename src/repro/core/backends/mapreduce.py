@@ -308,6 +308,9 @@ def mapreduce_histogram(log: EventLog,
     num_weeks, p)`` instead of unpack + ``histogram_fn`` — the Pallas
     ``segment_hist_packed_words`` kernel reduces the shuffled words
     without materializing the unpacked columns. Ignored by ``"columns"``.
+
+    The pack, the ordering and the rounds run under the ``malstone.exchange``
+    scope; the reducer's fold of each round nests ``malstone.combine``.
     """
     p = axis_size(axis_name)
     n = log.num_records
@@ -317,15 +320,16 @@ def mapreduce_histogram(log: EventLog,
     if max_rounds < 1:
         raise ValueError(f"max_rounds must be >= 1, got {max_rounds}")
     impl = resolve_exchange_impl(impl, num_sites, num_weeks, packed=packed)
-    if impl == "columns":
-        return _unpacked_shuffle_histogram(log, num_sites, num_weeks,
-                                           axis_name, capacity, histogram_fn,
-                                           max_rounds)
-    return _word_shuffle_histogram(
-        log, num_sites, num_weeks, axis_name, capacity, histogram_fn,
-        max_rounds,
-        order_words=_sort_words if impl == "sort" else _counting_words,
-        word_histogram_fn=word_histogram_fn)
+    with jax.named_scope("malstone.exchange"):
+        if impl == "columns":
+            return _unpacked_shuffle_histogram(
+                log, num_sites, num_weeks, axis_name, capacity,
+                histogram_fn, max_rounds)
+        return _word_shuffle_histogram(
+            log, num_sites, num_weeks, axis_name, capacity, histogram_fn,
+            max_rounds,
+            order_words=_sort_words if impl == "sort" else _counting_words,
+            word_histogram_fn=word_histogram_fn)
 
 
 def _shuffle_loop(body, carry0, *, capacity: int,
@@ -401,9 +405,12 @@ def _unpacked_shuffle_histogram(log: EventLog, num_sites: int,
         # Re-base strided site ids to local dense rows: local = site // P.
         # All received records satisfy site % P == my by construction;
         # guard anyway.
-        ok = shuffled.valid & ((shuffled.site_id % p) == my)
-        rebased = shuffled._replace(site_id=shuffled.site_id // p, valid=ok)
-        return histogram_fn(rebased, s_local, num_weeks), residual, rstats
+        with jax.named_scope("malstone.combine"):
+            ok = shuffled.valid & ((shuffled.site_id % p) == my)
+            rebased = shuffled._replace(site_id=shuffled.site_id // p,
+                                        valid=ok)
+            inc = histogram_fn(rebased, s_local, num_weeks)
+        return inc, residual, rstats
 
     # Normalize the pending-record pytree so the while carry has a fixed
     # structure (the shuffle only moves the four record columns + validity).
@@ -414,10 +421,11 @@ def _unpacked_shuffle_histogram(log: EventLog, num_sites: int,
     def body(carry):
         rounds, _, hist, pending, sent, deferred = carry
         inc, residual, rstats = one_round(pending)
-        return (rounds + 1,
-                jax.lax.psum(rstats.overflow, axis_name),
-                hist + inc,
-                residual,
+        rounds = rounds + 1
+        left = jax.lax.psum(rstats.overflow, axis_name)
+        with jax.named_scope("malstone.combine"):
+            hist = hist + inc
+        return (rounds, left, hist, residual,
                 sent + rstats.sent,
                 deferred + rstats.overflow)
 
@@ -501,9 +509,11 @@ def _word_shuffle_histogram(log: EventLog, num_sites: int,
         shipped = jax.lax.all_to_all(buf, axis_name, split_axis=0,
                                      concat_axis=0, tiled=True)
         left = jnp.sum(jnp.maximum(counts - (r + 1) * capacity, 0))
-        return (r + 1,
-                jax.lax.psum(left, axis_name),
-                hist + reduce_words(shipped.reshape(-1)),
+        r_next = r + 1
+        global_left = jax.lax.psum(left, axis_name)
+        with jax.named_scope("malstone.combine"):
+            hist = hist + reduce_words(shipped.reshape(-1))
+        return (r_next, global_left, hist,
                 sent + jnp.sum(live),
                 deferred + left)
 
@@ -564,12 +574,16 @@ def mapreduce_combiner_histogram(log: EventLog,
     why Sphere won Tables 4/5.
     """
     p = axis_size(axis_name)
-    local = histogram_fn(log, num_sites, num_weeks)   # [S, W, 2]
-    # regroup rows so destination d's strided sites (j % P == d) form a
-    # contiguous block: row (d, i) = site i * P + d
-    s_local = num_sites // p
-    blocks = local.reshape(s_local, p, num_weeks, 2).transpose(1, 0, 2, 3)
-    # shuffle: block d of every device -> device d; then sum the P partials
-    exch = jax.lax.all_to_all(blocks, axis_name, split_axis=0,
-                              concat_axis=0, tiled=True)
-    return jnp.sum(exch.reshape(p, s_local, num_weeks, 2), axis=0)
+    with jax.named_scope("malstone.exchange"):
+        with jax.named_scope("malstone.combine"):
+            local = histogram_fn(log, num_sites, num_weeks)   # [S, W, 2]
+        # regroup rows so destination d's strided sites (j % P == d) form a
+        # contiguous block: row (d, i) = site i * P + d
+        s_local = num_sites // p
+        blocks = local.reshape(s_local, p, num_weeks, 2).transpose(
+            1, 0, 2, 3)
+        # shuffle: block d of every device -> device d; then sum the P
+        # partials
+        exch = jax.lax.all_to_all(blocks, axis_name, split_axis=0,
+                                  concat_axis=0, tiled=True)
+        return jnp.sum(exch.reshape(p, s_local, num_weeks, 2), axis=0)

@@ -121,12 +121,13 @@ def _pad_sites(num_sites: int, parts: int) -> int:
 
 
 def _finalize(hist: jnp.ndarray, statistic: str) -> SpmResult:
-    if statistic == "A":
-        return spm_lib.malstone_a(hist)
-    if statistic == "B":
-        return spm_lib.malstone_b(hist)
-    if statistic == "B-fixed":
-        return spm_lib.malstone_b_fixed_denominator(hist)
+    with jax.named_scope("malstone.finalize"):
+        if statistic == "A":
+            return spm_lib.malstone_a(hist)
+        if statistic == "B":
+            return spm_lib.malstone_b(hist)
+        if statistic == "B-fixed":
+            return spm_lib.malstone_b_fixed_denominator(hist)
     raise ValueError(f"unknown statistic {statistic!r}")
 
 
@@ -680,31 +681,33 @@ def malstone_single_device(log: EventLog, num_sites: int,
 
 def pad_log_to(log: EventLog, target: int) -> EventLog:
     """Pad a log with invalid rows so the record dim divides the mesh."""
-    n = log.num_records
-    if n == target:
-        if log.valid is None:
-            return log._replace(valid=jnp.ones((n,), bool))
-        return log
-    pad = target - n
-    if pad < 0:
-        raise ValueError(
-            f"pad_log_to target ({target}) is smaller than the log's record "
-            f"count ({n}); pass a target >= num_records (it should be the "
-            f"record count rounded up to a multiple of mesh size x chunk)")
+    with jax.named_scope("malstone.read"):
+        n = log.num_records
+        if n == target:
+            if log.valid is None:
+                return log._replace(valid=jnp.ones((n,), bool))
+            return log
+        pad = target - n
+        if pad < 0:
+            raise ValueError(
+                f"pad_log_to target ({target}) is smaller than the log's "
+                f"record count ({n}); pass a target >= num_records (it "
+                f"should be the record count rounded up to a multiple of "
+                f"mesh size x chunk)")
 
-    def padcol(x, fill=0):
-        return jnp.concatenate([x, jnp.full((pad,), fill, x.dtype)])
+        def padcol(x, fill=0):
+            return jnp.concatenate([x, jnp.full((pad,), fill, x.dtype)])
 
-    valid = log.valid if log.valid is not None else jnp.ones((n,), bool)
-    return EventLog(
-        site_id=padcol(log.site_id),
-        entity_id=padcol(log.entity_id),
-        timestamp=padcol(log.timestamp),
-        mark=padcol(log.mark),
-        event_seq=None if log.event_seq is None else padcol(log.event_seq),
-        # sentinel, not 0: a zero fill gave padding rows the Event IDs
-        # (0, 0..pad) which collided with any real shard hashing to 0
-        shard_hash=None if log.shard_hash is None
-        else padcol(log.shard_hash, fill=PAD_SHARD_HASH),
-        valid=jnp.concatenate([valid, jnp.zeros((pad,), bool)]),
-    )
+        valid = log.valid if log.valid is not None else jnp.ones((n,), bool)
+        return EventLog(
+            site_id=padcol(log.site_id),
+            entity_id=padcol(log.entity_id),
+            timestamp=padcol(log.timestamp),
+            mark=padcol(log.mark),
+            event_seq=None if log.event_seq is None else padcol(log.event_seq),
+            # sentinel, not 0: a zero fill gave padding rows the Event IDs
+            # (0, 0..pad) which collided with any real shard hashing to 0
+            shard_hash=None if log.shard_hash is None
+            else padcol(log.shard_hash, fill=PAD_SHARD_HASH),
+            valid=jnp.concatenate([valid, jnp.zeros((pad,), bool)]),
+        )

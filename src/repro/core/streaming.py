@@ -105,14 +105,15 @@ def carry_init(backend: str, s_pad: int, num_weeks: int, axis_name):
     """Zero carry in the backend's accumulation layout; the ``mapreduce``
     carry also threads accumulated ShuffleStats. Runs INSIDE ``shard_map``
     (the mapreduce row count depends on the axis size)."""
-    if backend in ("streams", "sphere"):
-        return jnp.zeros((s_pad, num_weeks, 2), jnp.int32)
-    p = axis_size(axis_name)
-    owned = jnp.zeros((s_pad // p, num_weeks, 2), jnp.int32)
-    if backend == "mapreduce":
-        return (owned, _zero_stats())
-    if backend == "mapreduce_combiner":
-        return owned
+    with jax.named_scope("malstone.combine"):
+        if backend in ("streams", "sphere"):
+            return jnp.zeros((s_pad, num_weeks, 2), jnp.int32)
+        p = axis_size(axis_name)
+        owned = jnp.zeros((s_pad // p, num_weeks, 2), jnp.int32)
+        if backend == "mapreduce":
+            return (owned, _zero_stats())
+        if backend == "mapreduce_combiner":
+            return owned
     raise ValueError(f"unknown streaming backend {backend!r}")
 
 
@@ -153,10 +154,14 @@ def _accumulate_chunk(carry, chunk: EventLog, backend: str,
                       s_pad: int, num_weeks: int, axis_name,
                       histogram_fn, plan: ExchangePlan,
                       word_histogram_fn=None):
-    """Fold one chunk into the carry using the backend's dataflow."""
+    """Fold one chunk into the carry using the backend's dataflow.
+
+    The local combine and the carry add run under the ``malstone.combine``
+    scope; the MapReduce exchanges name their own (``malstone.exchange``)."""
     if backend in ("streams", "sphere"):
         # local combine only; the cross-device collective runs post-scan
-        return carry + histogram_fn(chunk, s_pad, num_weeks)
+        with jax.named_scope("malstone.combine"):
+            return carry + histogram_fn(chunk, s_pad, num_weeks)
     if backend == "mapreduce":
         hist, stats = carry
         owned, chunk_stats = mapreduce_histogram(
@@ -164,11 +169,15 @@ def _accumulate_chunk(carry, chunk: EventLog, backend: str,
             capacity_factor=plan.capacity_factor, histogram_fn=histogram_fn,
             max_rounds=plan.max_shuffle_rounds, impl=plan.impl,
             word_histogram_fn=word_histogram_fn)
-        return (hist + owned, _merge_stats(stats, chunk_stats))
+        with jax.named_scope("malstone.combine"):
+            hist = hist + owned
+        with jax.named_scope("malstone.exchange"):
+            return (hist, _merge_stats(stats, chunk_stats))
     if backend == "mapreduce_combiner":
         owned = mapreduce_combiner_histogram(
             chunk, s_pad, num_weeks, axis_name, histogram_fn=histogram_fn)
-        return carry + owned
+        with jax.named_scope("malstone.combine"):
+            return carry + owned
     raise ValueError(f"unknown streaming backend {backend!r}")
 
 
@@ -217,20 +226,25 @@ def post_scan_collective(carry, backend: str, s_pad: int,
     """Turn the per-device carry into the replicated full-site histogram
     (matching ``malstone_run``'s layout exactly) plus, for ``mapreduce``,
     the globally accumulated ShuffleStats (``None`` otherwise)."""
-    if backend == "streams":
-        return jax.lax.psum(carry, axis_name), None
-    if backend == "sphere":
-        owned = jax.lax.psum_scatter(carry, axis_name, scatter_dimension=0,
-                                     tiled=True)
-        return jax.lax.all_gather(owned, axis_name, axis=0, tiled=True), None
-    # mapreduce*: carry rows are strided (site = row * P + d): gather+unstride
-    stats = None
-    if backend == "mapreduce":
-        carry, stats = carry
-        stats = shuffle_stats(stats, axis_name)
-    gathered = jax.lax.all_gather(carry, axis_name, axis=0)  # [P, S/P, W, 2]
-    hist = jnp.transpose(gathered, (1, 0, 2, 3)).reshape(s_pad, num_weeks, 2)
-    return hist, stats
+    with jax.named_scope("malstone.exchange"):
+        if backend == "streams":
+            return jax.lax.psum(carry, axis_name), None
+        if backend == "sphere":
+            owned = jax.lax.psum_scatter(carry, axis_name,
+                                         scatter_dimension=0, tiled=True)
+            return jax.lax.all_gather(owned, axis_name, axis=0,
+                                      tiled=True), None
+        # mapreduce*: carry rows are strided (site = row * P + d):
+        # gather + unstride
+        stats = None
+        if backend == "mapreduce":
+            carry, stats = carry
+            stats = shuffle_stats(stats, axis_name)
+        # [P, S/P, W, 2]
+        gathered = jax.lax.all_gather(carry, axis_name, axis=0)
+        hist = jnp.transpose(gathered, (1, 0, 2, 3)).reshape(
+            s_pad, num_weeks, 2)
+        return hist, stats
 
 
 _post_scan_collective = post_scan_collective  # back-compat alias
@@ -393,14 +407,16 @@ def streaming_histogram_from_log(log_shard: EventLog, s_pad: int,
     def to_chunks(col):
         return None if col is None else col.reshape(num_chunks, chunk_records)
 
-    chunks = EventLog(*(to_chunks(col) for col in log_shard))
-
     def step(carry, chunk):
         return _accumulate_chunk(carry, chunk, backend, s_pad, num_weeks,
                                  axis_name, hist_fn, plan, word_fn), None
 
-    carry, _ = jax.lax.scan(
-        step, _carry_init(backend, s_pad, num_weeks, axis_name), chunks)
+    # reading the log: the reshape into chunks and the scan's per-chunk
+    # slice (the fold inside the scan names its own scopes)
+    with jax.named_scope("malstone.read"):
+        chunks = EventLog(*(to_chunks(col) for col in log_shard))
+        carry, _ = jax.lax.scan(
+            step, _carry_init(backend, s_pad, num_weeks, axis_name), chunks)
     return _post_scan_collective(carry, backend, s_pad, num_weeks, axis_name)
 
 

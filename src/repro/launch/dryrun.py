@@ -17,7 +17,7 @@ For every (architecture x input shape) cell, on BOTH production meshes
 
 plus the paper's own workload (malstone_step over the same meshes).
 Results (memory, flops, collective-bytes parsed from HLO) are persisted to
-results/dryrun/<cell>.json — benchmarks/roofline.py consumes them.
+results/dryrun/<cell>.json.
 
 Usage:
     PYTHONPATH=src python -m repro.launch.dryrun --arch llama3-8b \

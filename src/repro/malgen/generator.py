@@ -164,35 +164,36 @@ def generate_chunk(seed: SeedInfo, cfg: MalGenConfig,
     ``records_per_chunk`` (the mark table is derived from the same per-chunk
     keys). Memory is O(records_per_chunk) regardless of the global log size.
     """
-    c = records_per_chunk
-    n_marked = chunk_marked_records(cfg, c)
-    (k_msite, k_ment, k_mts, _bern,
-     k_usite, k_uent, k_uts) = chunk_keys(seed.key, chunk_id)
+    with jax.named_scope("malstone.generate"):
+        c = records_per_chunk
+        n_marked = chunk_marked_records(cfg, c)
+        (k_msite, k_ment, k_mts, _bern,
+         k_usite, k_uent, k_uts) = chunk_keys(seed.key, chunk_id)
 
-    m_site = sample_sites(k_msite, seed.marked_cdf, n_marked)
-    m_entity = jax.random.randint(k_ment, (n_marked,), 0, cfg.num_entities,
+        m_site = sample_sites(k_msite, seed.marked_cdf, n_marked)
+        m_entity = jax.random.randint(k_ment, (n_marked,), 0,
+                                      cfg.num_entities, dtype=jnp.int32)
+        m_ts = jax.random.randint(k_mts, (n_marked,), 0, cfg.span_seconds,
                                   dtype=jnp.int32)
-    m_ts = jax.random.randint(k_mts, (n_marked,), 0, cfg.span_seconds,
-                              dtype=jnp.int32)
 
-    n_unmarked = c - n_marked
-    u_site = sample_sites(k_usite, seed.unmarked_cdf, n_unmarked)
-    u_entity = jax.random.randint(k_uent, (n_unmarked,), 0, cfg.num_entities,
+        n_unmarked = c - n_marked
+        u_site = sample_sites(k_usite, seed.unmarked_cdf, n_unmarked)
+        u_entity = jax.random.randint(k_uent, (n_unmarked,), 0,
+                                      cfg.num_entities, dtype=jnp.int32)
+        u_ts = jax.random.randint(k_uts, (n_unmarked,), 0, cfg.span_seconds,
                                   dtype=jnp.int32)
-    u_ts = jax.random.randint(k_uts, (n_unmarked,), 0, cfg.span_seconds,
-                              dtype=jnp.int32)
 
-    site = jnp.concatenate([m_site, u_site])
-    entity = jnp.concatenate([m_entity, u_entity])
-    ts = jnp.concatenate([m_ts, u_ts])
+        site = jnp.concatenate([m_site, u_site])
+        entity = jnp.concatenate([m_entity, u_entity])
+        ts = jnp.concatenate([m_ts, u_ts])
 
-    # joined mark flag (paper §4)
-    mark = (seed.entity_mark_time[entity] <= ts).astype(jnp.int32)
+        # joined mark flag (paper §4)
+        mark = (seed.entity_mark_time[entity] <= ts).astype(jnp.int32)
 
-    shard_hash = jnp.full((c,), 1, jnp.uint32) * chunk_shard_hash(chunk_id)
-    event_seq = jnp.arange(c, dtype=jnp.uint32)
-    return EventLog(site_id=site, entity_id=entity, timestamp=ts, mark=mark,
-                    event_seq=event_seq, shard_hash=shard_hash)
+        shard_hash = jnp.full((c,), 1, jnp.uint32) * chunk_shard_hash(chunk_id)
+        event_seq = jnp.arange(c, dtype=jnp.uint32)
+        return EventLog(site_id=site, entity_id=entity, timestamp=ts,
+                        mark=mark, event_seq=event_seq, shard_hash=shard_hash)
 
 
 def generate_chunked_log(seed: SeedInfo, cfg: MalGenConfig,

@@ -113,9 +113,13 @@ class TestXlaFlags:
 class TestCompileCache:
     @pytest.fixture(autouse=True)
     def _restore_cache_dir(self):
-        was = jax.config.jax_compilation_cache_dir
+        keys = ("jax_compilation_cache_dir",
+                "jax_compilation_cache_include_metadata_in_key",
+                "jax_hlo_source_file_canonicalization_regex")
+        was = {k: getattr(jax.config, k) for k in keys}
         yield
-        jax.config.update("jax_compilation_cache_dir", was)
+        for k, v in was.items():
+            jax.config.update(k, v)
 
     def test_env_var_wins(self, monkeypatch, tmp_path):
         monkeypatch.setenv(env.COMPILE_CACHE_ENV, str(tmp_path))
