@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.common.types import EventLog
-from repro.malgen.powerlaw import sample_sites
+from repro.malgen.powerlaw import draw_sites
 from repro.malgen.seeding import (
     MalGenConfig,
     SeedInfo,
@@ -63,7 +63,8 @@ def generate_shard(seed: SeedInfo, cfg: MalGenConfig,
 
     k = jax.random.fold_in(seed.key, shard_id)
     k_site, k_ent, k_ts = jax.random.split(k, 3)
-    u_site = sample_sites(k_site, seed.unmarked_cdf, n_unmarked)
+    u_site = draw_sites(k_site, seed.unmarked_cdf, seed.unmarked_table,
+                        n_unmarked)
     u_entity = jax.random.randint(k_ent, (n_unmarked,), 0, cfg.num_entities,
                                   dtype=jnp.int32)
     u_ts = jax.random.randint(k_ts, (n_unmarked,), 0, cfg.span_seconds,
@@ -170,14 +171,16 @@ def generate_chunk(seed: SeedInfo, cfg: MalGenConfig,
         (k_msite, k_ment, k_mts, _bern,
          k_usite, k_uent, k_uts) = chunk_keys(seed.key, chunk_id)
 
-        m_site = sample_sites(k_msite, seed.marked_cdf, n_marked)
+        m_site = draw_sites(k_msite, seed.marked_cdf, seed.marked_table,
+                            n_marked)
         m_entity = jax.random.randint(k_ment, (n_marked,), 0,
                                       cfg.num_entities, dtype=jnp.int32)
         m_ts = jax.random.randint(k_mts, (n_marked,), 0, cfg.span_seconds,
                                   dtype=jnp.int32)
 
         n_unmarked = c - n_marked
-        u_site = sample_sites(k_usite, seed.unmarked_cdf, n_unmarked)
+        u_site = draw_sites(k_usite, seed.unmarked_cdf,
+                            seed.unmarked_table, n_unmarked)
         u_entity = jax.random.randint(k_uent, (n_unmarked,), 0,
                                       cfg.num_entities, dtype=jnp.int32)
         u_ts = jax.random.randint(k_uts, (n_unmarked,), 0, cfg.span_seconds,
@@ -322,7 +325,8 @@ def generate_shard_device(seed: SeedInfo, cfg: MalGenConfig,
     k_site, k_ent, k_ts = jax.random.split(k, 3)
 
     def draw_unmarked(n: int):
-        return (sample_sites(k_site, seed.unmarked_cdf, n),
+        return (draw_sites(k_site, seed.unmarked_cdf, seed.unmarked_table,
+                           n),
                 jax.random.randint(k_ent, (n,), 0, cfg.num_entities,
                                    dtype=jnp.int32),
                 jax.random.randint(k_ts, (n,), 0, cfg.span_seconds,

@@ -24,9 +24,11 @@ import jax.numpy as jnp
 
 from repro.common.types import NEVER_MARKED, SECONDS_PER_WEEK, SECONDS_PER_YEAR
 from repro.malgen.powerlaw import (
+    SITE_TABLE_SIZE,
+    draw_sites,
     masked_site_cdf,
     power_law_weights,
-    sample_sites,
+    site_table,
 )
 
 
@@ -57,6 +59,12 @@ class SeedInfo(NamedTuple):
     makes every generation program (eager oracle, outer-jitted scan,
     standalone overlap programs, multi-process SPMD) sample against the
     *same* table, so the log is bit-identical everywhere.
+
+    The two site tables (``powerlaw.site_table``) are seed data for the same
+    reason: each is the CDF's search evaluated once over every float32
+    uniform draw, so generation looks a draw up instead of searching for it.
+    Both are None for a log of fewer records than a table has entries,
+    which generation then searches.
     """
     key: jax.Array                 # the root PRNG key (regeneration handle)
     marked_mask: jnp.ndarray       # bool [num_sites]
@@ -65,19 +73,26 @@ class SeedInfo(NamedTuple):
     num_marked_events: int         # length of the global marked-event stream
     marked_cdf: jnp.ndarray        # float32 [num_sites] CDF over marked sites
     unmarked_cdf: jnp.ndarray      # float32 [num_sites] CDF over the rest
+    marked_table: jnp.ndarray | None    # int32 [2^23] site of each draw
+    unmarked_table: jnp.ndarray | None  # int32 [2^23] site of each draw
 
     @property
     def seed_bytes(self) -> int:
-        """Scatter payload size — the paper's Table 3 memory concern."""
+        """Scatter payload size — the paper's Table 3 memory concern.
+
+        The site tables are not counted: each node derives them from the
+        CDFs, which are."""
         return (self.marked_mask.size * 1 + self.entity_mark_time.size * 4
                 + self.site_weights.size * 4
                 + self.marked_cdf.size * 4 + self.unmarked_cdf.size * 4 + 32)
 
 
-def _site_tables(key: jax.Array, cfg: MalGenConfig):
-    """(k_events, site_weights, marked_mask) — shared by both seeding paths
-    so a given root key yields identical site popularity / marked-site sets
-    whether the log is later generated shard-wise or chunk-wise."""
+def _site_tables(key: jax.Array, cfg: MalGenConfig, records: int):
+    """(k_events, site_weights, marked_mask, marked_cdf, unmarked_cdf,
+    marked_table, unmarked_table) — shared by both seeding paths so a given
+    root key yields identical site popularity / marked-site sets whether the
+    log is later generated shard-wise or chunk-wise. ``records`` is the
+    log's size, which decides whether the site tables are built."""
     k_perm, k_marked, k_events = jax.random.split(key, 3)
 
     # Popularity decoupled from site id ordering.
@@ -95,25 +110,32 @@ def _site_tables(key: jax.Array, cfg: MalGenConfig):
     # masked_site_cdf).
     marked_cdf = masked_site_cdf(weights, marked_mask)
     unmarked_cdf = masked_site_cdf(weights, ~marked_mask)
-    return k_events, weights, marked_mask, marked_cdf, unmarked_cdf
+    # A table costs a search of each of its entries: a log of fewer records
+    # than that draws fewer sites than building the table would search.
+    if records < SITE_TABLE_SIZE:
+        return (k_events, weights, marked_mask, marked_cdf, unmarked_cdf,
+                None, None)
+    return (k_events, weights, marked_mask, marked_cdf, unmarked_cdf,
+            site_table(marked_cdf), site_table(unmarked_cdf))
 
 
 def make_seed(key: jax.Array, cfg: MalGenConfig,
               total_records: int) -> SeedInfo:
     """Phase 1. ``total_records`` is the global record budget; the marked
     stream gets ``round(total * marked_event_fraction)`` events."""
-    k_events, weights, marked_mask, marked_cdf, unmarked_cdf = \
-        _site_tables(key, cfg)
+    (k_events, weights, marked_mask, marked_cdf, unmarked_cdf,
+     marked_table, unmarked_table) = _site_tables(key, cfg, total_records)
 
     num_marked_events = max(1, int(round(total_records * cfg.marked_event_fraction)))
     entity_mark_time = _derive_mark_table(
-        k_events, cfg, marked_cdf, num_marked_events)
+        k_events, cfg, marked_cdf, marked_table, num_marked_events)
 
     return SeedInfo(key=key, marked_mask=marked_mask,
                     entity_mark_time=entity_mark_time,
                     site_weights=weights,
                     num_marked_events=num_marked_events,
-                    marked_cdf=marked_cdf, unmarked_cdf=unmarked_cdf)
+                    marked_cdf=marked_cdf, unmarked_cdf=unmarked_cdf,
+                    marked_table=marked_table, unmarked_table=unmarked_table)
 
 
 def marked_event_stream(seed: SeedInfo, cfg: MalGenConfig):
@@ -124,13 +146,13 @@ def marked_event_stream(seed: SeedInfo, cfg: MalGenConfig):
     trick: bytes moved = seed, not events.
     """
     k_events = jax.random.split(seed.key, 3)[2]
-    return _marked_events(k_events, cfg, seed.marked_cdf,
+    return _marked_events(k_events, cfg, seed.marked_cdf, seed.marked_table,
                           seed.num_marked_events)
 
 
-def _marked_events(k_events, cfg, marked_cdf, num_events):
+def _marked_events(k_events, cfg, marked_cdf, marked_table, num_events):
     k_site, k_ent, k_ts, _ = jax.random.split(k_events, 4)
-    site = sample_sites(k_site, marked_cdf, num_events)
+    site = draw_sites(k_site, marked_cdf, marked_table, num_events)
     entity = jax.random.randint(k_ent, (num_events,), 0, cfg.num_entities,
                                 dtype=jnp.int32)
     ts = jax.random.randint(k_ts, (num_events,), 0, cfg.span_seconds,
@@ -182,7 +204,9 @@ def make_seed_streaming(key: jax.Array, cfg: MalGenConfig,
     layout-bound: it corresponds to the log produced by ``generate_chunk``
     over ``chunk_id in [0, num_chunks)`` at this ``records_per_chunk``.
     """
-    _, weights, marked_mask, marked_cdf, unmarked_cdf = _site_tables(key, cfg)
+    (_, weights, marked_mask, marked_cdf, unmarked_cdf,
+     marked_table, unmarked_table) = _site_tables(
+        key, cfg, num_chunks * records_per_chunk)
     n_marked = chunk_marked_records(cfg, records_per_chunk)
 
     def step(earliest, chunk_id):
@@ -203,7 +227,8 @@ def make_seed_streaming(key: jax.Array, cfg: MalGenConfig,
     return SeedInfo(key=key, marked_mask=marked_mask,
                     entity_mark_time=mark_time, site_weights=weights,
                     num_marked_events=num_chunks * n_marked,
-                    marked_cdf=marked_cdf, unmarked_cdf=unmarked_cdf)
+                    marked_cdf=marked_cdf, unmarked_cdf=unmarked_cdf,
+                    marked_table=marked_table, unmarked_table=unmarked_table)
 
 
 def _apply_mark_delay(earliest: jnp.ndarray, cfg: MalGenConfig) -> jnp.ndarray:
@@ -214,8 +239,9 @@ def _apply_mark_delay(earliest: jnp.ndarray, cfg: MalGenConfig) -> jnp.ndarray:
         earliest + cfg.mark_delay).astype(jnp.int32)
 
 
-def _derive_mark_table(k_events, cfg, marked_cdf, num_events):
-    site, entity, ts = _marked_events(k_events, cfg, marked_cdf, num_events)
+def _derive_mark_table(k_events, cfg, marked_cdf, marked_table, num_events):
+    site, entity, ts = _marked_events(k_events, cfg, marked_cdf, marked_table,
+                                      num_events)
     _, _, _, k_bern = jax.random.split(k_events, 4)
     marks_entity = jax.random.bernoulli(k_bern, cfg.p_mark, (num_events,))
 
