@@ -102,6 +102,7 @@ def count_tiles_pallas(dest: jnp.ndarray, *, p_pad: int,
         out_specs=pl.BlockSpec((None, 1, p_pad), lambda t: (t, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((n_tiles, 1, p_pad), jnp.int32),
         interpret=interpret,
+        name="count_tiles",
     )(dest)
 
 
@@ -217,4 +218,5 @@ def scatter_tiles_pallas(dest: jnp.ndarray, words: jnp.ndarray,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
+        name="scatter_tiles",
     )(base, counts, dest, words, jnp.zeros((out_rows, LANES), jnp.int32))
